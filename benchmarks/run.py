@@ -90,7 +90,10 @@ def main() -> None:
 
     import importlib
 
+    from repro import compile_cache
     from repro.obs import metrics
+
+    compile_cache.enable()
 
     print("name,us_per_call,derived")
     failed = []

@@ -41,16 +41,21 @@ def main():
     params = model.init(jax.random.PRNGKey(0))
     prompts = np.random.default_rng(0).integers(
         0, cfg.vocab_size, (4, 8)).astype(np.int32)
+    # the Pallas ingest kernel compiles for TPU only; elsewhere this
+    # walkthrough runs its body in interpret mode, and says so
+    interpret = jax.default_backend() != "tpu"
 
     for quant, kernel in ((0, False), (0, True), (8, False)):
         server = PDServer(model, params, max_seq=64, page_tokens=8,
                           quantize_bits=quant)
         t0 = time.monotonic()
-        toks, stats = server.serve(prompts, n_steps=8, use_kernel=kernel)
+        toks, stats = server.serve(prompts, n_steps=8, use_kernel=kernel,
+                                   interpret=interpret)
         dt = time.monotonic() - t0
         ref = direct_reference(model, params, prompts, 8, 64)
         match = "EXACT" if np.array_equal(toks, ref) else "differs (quant)"
-        print(f"quant={quant} pallas_ingest={kernel}: {dt:.2f}s, "
+        print(f"quant={quant} pallas_ingest={kernel} "
+              f"(interpret={interpret and kernel}): {dt:.2f}s, "
               f"payload={stats.payload_bytes/1e6:.2f}MB, "
               f"header={stats.header_bytes}B -> vs direct: {match}")
     print("tokens:", toks[0].tolist())

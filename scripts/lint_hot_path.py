@@ -2,7 +2,7 @@
 """Static check: no host-device synchronization inside compiled dispatch.
 
 PR 7's contract is ONE fused launch per flush: the jitted entry points
-(`kernels/*/ops.py`, anything under ``@jax.jit`` / ``@compat.jit`` /
+(`kernels/*/ops.py`, anything under ``@jax.jit`` /
 ``@partial(jit, ...)``) must stay pure traced array code. A host sync
 smuggled into a traced body — ``np.asarray(tracer)``,
 ``x.block_until_ready()``, ``.item()`` / ``.tolist()``, ``float(x)`` on
@@ -49,7 +49,7 @@ CONCRETIZERS = {"float", "int", "bool"}
 
 
 def _is_jit_expr(node: ast.AST) -> bool:
-    """``jit`` / ``jax.jit`` / ``compat.jit`` (any dotted .jit)."""
+    """``jit`` / ``jax.jit`` (any dotted .jit)."""
     if isinstance(node, ast.Name):
         return node.id == "jit"
     return isinstance(node, ast.Attribute) and node.attr == "jit"

@@ -128,8 +128,8 @@ class QPContext:
                 if self.coalesce_writes:
                     flat = wr_scatter_ops.gather_records(arr, offs, L)
                 else:
-                    idx = offs[:, None].astype(np.int64) * L + np.arange(L)
-                    flat = jnp.take(arr.ravel(), jnp.asarray(idx), axis=0)
+                    flat = jnp.take(jnp.reshape(arr, (-1, L)),
+                                    jnp.asarray(offs, jnp.int32), axis=0)
                 self.dma_launches += 1
                 c = 0
                 for i, d in reads:

@@ -4,8 +4,9 @@
 the logical->physical shadow table. On TPU the scatter runs as the
 kernels/kv_ingest Pallas kernel whose BlockSpec double-buffering pins VMEM
 residency to two tiles regardless of cache size (the "there is always an
-invalidated cacheline" invariant); elsewhere it is a jnp scatter with the
-same semantics (the kernel's ref oracle).
+invalidated cacheline" invariant); without the kernel it is a jnp scatter
+with the same semantics (the kernel's ref oracle). The kernel runs in
+interpret mode only when the caller asks for it (`interpret=True`).
 """
 from __future__ import annotations
 
@@ -17,7 +18,7 @@ from repro.core.shadow import ShadowTable
 
 
 def ingest(pages, payload, logical_ids, shadow: ShadowTable | None = None,
-           *, use_kernel: bool = False, interpret: bool = True):
+           *, use_kernel: bool = False, interpret: bool = False):
     """pages: (n_pages, page_tokens, KVH, hd); payload: (n, page_tokens,
     KVH, hd); logical_ids: (n,) page ids (logical if shadow given)."""
     ids = np.asarray(logical_ids)

@@ -18,10 +18,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.descriptors import TransferPlan
 from repro.models import module as mod
 from repro.obs import metrics

@@ -16,24 +16,25 @@ from __future__ import annotations
 
 from functools import partial
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.kernels.desc_ring import desc_ring
 from repro.obs import metrics
 
-@partial(compat.jit, donate_argnums=(0, 1))
+
+@partial(jax.jit, donate_argnums=(0, 1))
 def _produce(slots, flags, batch, head):
     return desc_ring.produce(slots, flags, batch, head)
 
 
-@compat.jit
+@jax.jit
 def _consume(slots, flags, tail):
     return desc_ring.consume(slots, flags, tail)
 
 
-@partial(compat.jit, donate_argnums=(0, 1))
+@partial(jax.jit, donate_argnums=(0, 1))
 def _produce_consume(slots, flags, batch, head, tail):
     return desc_ring.produce_consume(slots, flags, batch, head, tail)
 

@@ -1,24 +1,16 @@
-"""Paged KV-cache ingest kernel — the FlexiNS RX path itself (T2).
+"""Paged KV-cache ingest — the FlexiNS RX path itself (T2).
 
 Incoming payload tiles (one KV page each) are scattered into the paged
-cache at physical page ids resolved by the shadow table. The page id
-stream is scalar-prefetched (the "header" rides SMEM, the payload rides
-the double-buffered VMEM stream); each visited output block is simply
-overwritten — the unvisited remainder of the cache is carried through
-input/output aliasing, so no byte of the (unbounded) working set is ever
-resident beyond the two in-flight tiles.
+cache at physical page ids resolved by the shadow table. That is exactly
+the fused WRITE-run scatter: the page id stream is scalar-prefetched
+(the "header" rides SMEM, the payload rides the double-buffered VMEM
+stream), each visited page block is overwritten in place and the rest of
+the cache is carried through input/output aliasing, so no byte of the
+(unbounded) working set is ever resident beyond the two in-flight tiles.
 """
 from __future__ import annotations
 
-import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-
-def _kernel(ids_ref, payload_ref, pages_in_ref, out_ref):
-    del ids_ref, pages_in_ref
-    out_ref[...] = payload_ref[...]
+from repro.kernels.wr_scatter.wr_scatter import wr_scatter
 
 
 def kv_ingest(pages, payload, page_ids, *, interpret=False):
@@ -26,27 +18,4 @@ def kv_ingest(pages, payload, page_ids, *, interpret=False):
 
     Returns updated pages; duplicate ids are caller error (shadow table
     allocates unique physical pages)."""
-    n = payload.shape[0]
-    P = pages.shape[0]
-    blk = pages.shape[1:]
-    flat_pages = pages.reshape(P, -1)
-    flat_payload = payload.reshape(n, -1).astype(flat_pages.dtype)
-    F = flat_pages.shape[1]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1, F), lambda i, ids: (i, 0)),
-            pl.BlockSpec((1, F), lambda i, ids: (ids[i], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, F), lambda i, ids: (ids[i], 0)),
-    )
-    out = pl.pallas_call(
-        _kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((P, F), flat_pages.dtype),
-        input_output_aliases={2: 0},       # pages are updated in place
-        interpret=interpret,
-    )(jnp.asarray(page_ids, jnp.int32), flat_payload, flat_pages)
-    return out.reshape((P,) + blk)
+    return wr_scatter(pages, payload, page_ids, interpret=interpret)

@@ -1,4 +1,5 @@
-"""Jit'd wrapper for the kv_ingest kernel."""
+"""Jit'd wrapper for the kv_ingest kernel. `interpret=True` runs the
+Pallas body on the CPU; only a caller that asks for it gets it."""
 from __future__ import annotations
 
 from functools import partial
@@ -10,9 +11,7 @@ from repro.kernels.kv_ingest import ref
 
 
 @partial(jax.jit, static_argnames=("interpret",), donate_argnums=(0,))
-def kv_ingest(pages, payload, page_ids, *, interpret=None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+def kv_ingest(pages, payload, page_ids, *, interpret=False):
     return _kernel(pages, payload, page_ids, interpret=interpret)
 
 

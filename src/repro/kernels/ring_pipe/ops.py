@@ -1,4 +1,5 @@
-"""Jit'd wrapper for ring_consume."""
+"""Jit'd wrapper for ring_consume. `interpret=True` runs the Pallas body
+on the CPU; only a caller that asks for it gets it."""
 from __future__ import annotations
 
 from functools import partial
@@ -10,9 +11,7 @@ from repro.kernels.ring_pipe import ref
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def ring_consume(slots, src_idx, *, interpret=None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+def ring_consume(slots, src_idx, *, interpret=False):
     return _kernel(slots, src_idx, interpret=interpret)
 
 

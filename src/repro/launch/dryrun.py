@@ -25,7 +25,7 @@ from repro.parallel import sharding
 from repro.train import optimizer as optim
 from repro.train.train_loop import make_train_step
 from repro.utils import costmodel, hlo_cost, roofline
-from repro import compat, perf
+from repro import perf
 
 
 def lower_cell(arch: str, shape_name: str, multi_pod: bool):
@@ -71,7 +71,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool):
 
         compiled = lowered.compile()
         mem = compiled.memory_analysis()
-        cost = compat.cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         print(f"--- {arch} x {shape_name} x "
               f"{'multi' if multi_pod else 'single'} ---")
         print(f"memory_analysis: args={mem.argument_size_in_bytes/1e9:.3f}GB "

@@ -1,32 +1,24 @@
 """Production meshes. A FUNCTION (never a module-level constant) so that
 importing this module never touches jax device state.
 
-`make_mesh` is the version-compat entry point: ``jax.sharding.AxisType``
-(and the ``axis_types=`` kwarg) only exists on jax >= 0.6; on 0.4.x the
-plain ``jax.make_mesh(devices, axes)`` call is the whole API. Every
-module (and test subprocess snippet) builds meshes through this helper —
-never call ``jax.make_mesh(..., axis_types=...)`` directly.
+`make_mesh` builds every mesh of the repo with `Auto` axes: the
+sharding rules in `repro.parallel.sharding` place arrays by
+constraint, not by explicit-axis typing.
 """
 from __future__ import annotations
 
 import jax
 
-from repro.compat import HAS_AXIS_TYPE
-
 
 def make_mesh(shape: tuple, axes: tuple):
-    if HAS_AXIS_TYPE:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,)
-                             * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_fabric_mesh(pods: int, devices_per_pod: int = 1):
     """The verbs fabric's second mesh axis: a (`pod`, `device`) grid for
-    routed multi-pod QPs. Built through `make_mesh` (the version-compat
-    shim — never raw ``jax.make_mesh``). Returns ``None`` when the rig
-    does not expose exactly ``pods * devices_per_pod`` devices (the
+    routed multi-pod QPs, built through `make_mesh`. Returns ``None``
+    when the rig does not expose exactly ``pods * devices_per_pod`` devices (the
     1-device CPU test rig): the fabric then routes over the logical grid
     only, with identical addressing semantics."""
     if pods * devices_per_pod != len(jax.devices()):

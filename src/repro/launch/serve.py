@@ -13,6 +13,7 @@ import time
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.configs.base import get_config, reduced as reduce_cfg
 from repro.models.registry import build_model
 from repro.serve.engine import ServeEngine
@@ -31,6 +32,7 @@ def main():
                    help="prefill/decode disaggregation path")
     p.add_argument("--quantize-kv", action="store_true")
     args = p.parse_args()
+    compile_cache.enable()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -9,10 +9,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.models.layers import act_fn, linear, linear_spec
 from repro.parallel import sharding
 

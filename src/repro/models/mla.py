@@ -77,7 +77,7 @@ def mla_forward_sp(params, x, positions, cfg, *, q_chunk=512, kv_chunk=1024):
     insight applied to the training plane (EXPERIMENTS.md §Perf iter 6)."""
     from jax import lax
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.models.attention import chunked_attention
 
     a = cfg.mla

@@ -25,10 +25,9 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.models.layers import act_fn
 from repro.models.module import Spec
 from repro.models import ffn

@@ -13,9 +13,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 
-from repro.compat import shard_map
 from repro.models import ffn, mla, moe, rglru, ssm
 from repro.models.attention import chunked_attention
 from repro.models.layers import (embed, embedding_spec, proj_spec, rmsnorm,
@@ -91,14 +90,19 @@ def attn_spec(cfg) -> dict:
     D, H, KVH = cfg.d_model, cfg.n_heads, cfg.n_kv_heads
     hd = cfg.resolved_head_dim
     bd = (1, 2) if cfg.qkv_bias else None
+    # explicit fan-in scales: the default (shape[-2]) would read heads /
+    # head_dim as the fan-in, and a random model drawn that way has
+    # near-one-hot attention whose argmax flips under bf16 rounding
+    s_in, s_out = D ** -0.5, (H * hd) ** -0.5
     return {
         "wq": proj_spec((D, H, hd), ("embed", "heads", "head_dim"),
-                        bias_dims=bd),
+                        bias_dims=bd, scale=s_in),
         "wk": proj_spec((D, KVH, hd), ("embed", "kv_heads", "head_dim"),
-                        bias_dims=bd),
+                        bias_dims=bd, scale=s_in),
         "wv": proj_spec((D, KVH, hd), ("embed", "kv_heads", "head_dim"),
-                        bias_dims=bd),
-        "wo": proj_spec((H, hd, D), ("heads", "head_dim", "embed")),
+                        bias_dims=bd, scale=s_in),
+        "wo": proj_spec((H, hd, D), ("heads", "head_dim", "embed"),
+                        scale=s_out),
     }
 
 

@@ -22,12 +22,11 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.attention import (chunked_attention, decode_partials,
                                     finalize_partials)
-from repro.compat import shard_map
 from repro.parallel import sharding
 
 

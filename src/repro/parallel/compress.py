@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 
 
 def _quant(x, axis=-1):
@@ -37,7 +36,7 @@ def compressed_psum_mean(x, axis_name: str, *, return_residual: bool = False):
     """Mean over `axis_name` with int8 wire traffic. Call inside shard_map.
 
     x: (..., F) f32 with F divisible by the axis size."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     flat = x.reshape(-1)
     F = flat.shape[0]
     assert F % n == 0, (F, n)

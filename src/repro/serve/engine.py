@@ -83,7 +83,6 @@ class ServeEngine:
                            lambda e: sum(1 for r in e.requests.values()
                                          if not r.done))
         self.model = model
-        self.params = params
         self.cfg = model.cfg
         self.max_batch = max_batch
         self.max_seq = max_seq
@@ -107,6 +106,11 @@ class ServeEngine:
         # produce_consume launch end to end (submits are unsignaled
         # inline SENDs, so the submit side is launch-free)
         self.gid = gid or self.fabric.gids[0]
+        # on a grid with one chip per gid, this pod's params and cache
+        # live on its own chip (None: the logical grid, default device)
+        self.device = self.fabric.device_of(self.gid)
+        self.params = params if self.device is None else \
+            jax.device_put(params, self.device)
         cm = self.fabric.node(self.gid)
         # `service` publishes the listener for `fabric.discover()` — a
         # front-end Router finds decode pods by name, not by object
@@ -139,12 +143,15 @@ class ServeEngine:
             # cache state on this pod's protection domain: one MR per
             # cache leaf, record = one page — remotely addressable
             self.pool = PagePool(model, cm.pd, max_batch=max_batch,
-                                 max_seq=max_seq, page_tokens=page_tokens)
+                                 max_seq=max_seq, page_tokens=page_tokens,
+                                 device=self.device)
             self._paged_step = make_paged_step(model, self.pool)
             self.caches = None
         else:
             self.pool = None
             self.caches = model.init_cache(max_batch, max_seq)
+            if self.device is not None:
+                self.caches = jax.device_put(self.caches, self.device)
         self._decode = jax.jit(model.decode_step)
         self._prefill = jax.jit(model.prefill)
 

@@ -67,7 +67,7 @@ class PagedKVPool:
         self.shadow.release_region(alloc.region)
 
     def ingest(self, alloc: SeqAllocation, kv: jnp.ndarray,
-               use_kernel: bool = False):
+               use_kernel: bool = False, interpret: bool = False):
         """kv: (S, ...) contiguous prefill output -> paged pool (T2 path)."""
         S = kv.shape[0]
         n_pages = len(alloc.logical_pages)
@@ -76,7 +76,8 @@ class PagedKVPool:
             kv = jnp.pad(kv, [(0, pad)] + [(0, 0)] * (kv.ndim - 1))
         tiles = kv.reshape((n_pages, self.page_tokens) + kv.shape[1:])
         self.pages = rx_engine.ingest(self.pages, tiles, alloc.logical_pages,
-                                      self.shadow, use_kernel=use_kernel)
+                                      self.shadow, use_kernel=use_kernel,
+                                      interpret=interpret)
 
     def gather(self, alloc: SeqAllocation, n_tokens: int) -> jnp.ndarray:
         tiles = rx_engine.gather_pages(self.pages, alloc.logical_pages,

@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import jit
 from repro.models.module import is_spec
 from repro.obs import metrics
 
@@ -97,13 +96,15 @@ class PagePool:
     are shared across leaves: an allocation is one id list, valid in
     every leaf's region. The slot -> page table (``(max_batch,
     pages_per_slot)`` int32, 0 = null page) is the indirection the paged
-    decode step consumes."""
+    decode step consumes. `device` places the page regions on one chip
+    (None: the default device)."""
 
     pages_allocated = metrics.counter_attr()
     pages_freed = metrics.counter_attr()
 
     def __init__(self, model, pd, *, max_batch: int, max_seq: int,
-                 page_tokens: int = 16, n_pages: int | None = None):
+                 page_tokens: int = 16, n_pages: int | None = None,
+                 device=None):
         metrics.instance_scope(self, "pagepool", indexed=True)
         if max_seq % page_tokens:
             raise ValueError(
@@ -128,7 +129,8 @@ class PagePool:
         for i, spec in enumerate(specs):
             shp = tuple(spec.shape)           # (L, B, S, *feat)
             page_shape = (self.n_pages, shp[0], page_tokens) + shp[3:]
-            arr = jnp.zeros(page_shape, jnp.dtype(spec.dtype or cfg_dtype))
+            arr = jnp.zeros(page_shape, jnp.dtype(spec.dtype or cfg_dtype),
+                            device=device)
             self.mrs.append(self.pd.reg_mr(f"{idx}/leaf{i}", arr))
         self._free = list(range(self.n_pages - 1, 0, -1))   # page 0 = null
         self.table = np.zeros((max_batch, self.pages_per_slot), np.int32)
@@ -250,4 +252,4 @@ def make_paged_step(model, pool: PagePool):
                 r.reshape((B * ppslot,) + pg.shape[1:])))
         return logits, outs
 
-    return jit(step)
+    return jax.jit(step)

@@ -73,11 +73,13 @@ class PDServer:
 
     # -- decode pod (with paged ingest) ----------------------------------
     def ingest_and_decode(self, caches, first_tokens, prefill_len: int,
-                          n_steps: int = 8, use_kernel: bool = False):
+                          n_steps: int = 8, use_kernel: bool = False,
+                          interpret: bool = False):
         """Ingest transferred caches through the paged pool (T2), gather
         back to the decode layout, then run greedy decode steps."""
         caches = pad_caches(caches, prefill_len, self.max_seq)
-        caches = self._page_roundtrip(caches, use_kernel=use_kernel)
+        caches = self._page_roundtrip(caches, use_kernel=use_kernel,
+                                      interpret=interpret)
         B = first_tokens.shape[0]
         toks = jnp.asarray(first_tokens)[:, None].astype(jnp.int32)
         out = [np.asarray(toks[:, 0])]
@@ -91,7 +93,7 @@ class PDServer:
             pos = pos + 1
         return np.stack(out, 1)
 
-    def _page_roundtrip(self, caches, use_kernel: bool):
+    def _page_roundtrip(self, caches, use_kernel: bool, interpret: bool):
         """Every seq-indexed cache leaf takes the paged ingest+gather path."""
         def one(a):
             if a.ndim < 3 or a.shape[2] != self.max_seq:
@@ -106,19 +108,21 @@ class PDServer:
                     page_tokens=self.page_tokens,
                     feature_shape=kv.shape[1:], dtype=kv.dtype)
                 alloc = pool.allocate(self.max_seq)
-                pool.ingest(alloc, kv, use_kernel=use_kernel)
+                pool.ingest(alloc, kv, use_kernel=use_kernel,
+                            interpret=interpret)
                 outs.append(pool.gather(alloc, self.max_seq))
             return jnp.stack(outs).reshape(lead + (self.max_seq,) + a.shape[3:])
         return jax.tree.map(one, caches)
 
     # -- end to end -------------------------------------------------------
     def serve(self, prompts: np.ndarray, n_steps: int = 8, staged=False,
-              use_kernel: bool = False):
+              use_kernel: bool = False, interpret: bool = False):
         first, caches, plen = self.prefill(prompts)
         caches, stats = self.transfer(caches, prompts.shape[0], plen,
                                       staged=staged)
         toks = self.ingest_and_decode(caches, first, plen, n_steps,
-                                      use_kernel=use_kernel)
+                                      use_kernel=use_kernel,
+                                      interpret=interpret)
         return toks, stats
 
 
@@ -154,13 +158,18 @@ class PrefillPod:
         self.prefill_compiles = 0
         self.requests_processed = 0
         self.model = model
-        self.params = params
         self.fabric = fabric
         self.gid = gid
+        # on a grid with one chip per gid, the pod's params and staging
+        # pages live on its own chip; migrations then cross chips
+        self.device = fabric.device_of(gid)
+        self.params = params if self.device is None else \
+            jax.device_put(params, self.device)
         self.max_seq = max_seq
         self.bucketed = bucketable(model)
         self.pool = PagePool(model, fabric.node(gid).pd, max_batch=1,
-                             max_seq=max_seq, page_tokens=page_tokens)
+                             max_seq=max_seq, page_tokens=page_tokens,
+                             device=self.device)
         self.kv = KVTransferEngine(model, 1, max_seq, fabric=fabric,
                                    src_gid=gid, decode_gids=decode_gids)
         self._prefill = jax.jit(model.prefill)

@@ -20,10 +20,9 @@ _COMP_HDR = re.compile(r"^(?:ENTRY )?%?([\w.\-]+)\s*\(.*\)\s*->.*\{")
 _DEF_RE = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(.*)$")
 _SHAPE_RE = re.compile(r"^([a-z]\w*)\[([0-9,]*)\]")
 _TUPLE_SHAPES = re.compile(r"([a-z]\w*)\[([0-9,]*)\]")
-# operands may carry a type prefix ("dot(f32[8,64]{1,0} %a, ...)" on the
-# 0.4.x HLO printer) or be bare ("dot(%a, %b)" on newer XLA); the layout
-# braces can hold tiling suffixes like {1,0:T(8,128)(2,1)} on TPU
-_OPERAND = r"(?:[a-z]\w*\[[0-9,]*\](?:\{[^}]*\})?\s+)?%([\w.\-]+)"
+# operands print bare ("dot(%a, %b)"); the result's layout braces can
+# hold tiling suffixes like {1,0:T(8,128)(2,1)} on TPU
+_OPERAND = r"%([\w.\-]+)"
 _DOT_RE = re.compile(
     r"^([a-z]\w*)\[([0-9,]*)\][^=]*?\bdot\(" + _OPERAND + r",\s*"
     + _OPERAND + r"\)"

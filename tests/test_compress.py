@@ -13,7 +13,7 @@ def test_compressed_psum_accuracy_and_wire_bytes():
         os.environ["JAX_PLATFORMS"] = "cpu"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.launch.mesh import make_mesh
         from repro.parallel.compress import compressed_psum_mean
         from repro.utils import hlo_cost

@@ -15,7 +15,7 @@ Three contracts under test:
 
 Plus kernel-level ops-vs-ref checks (tests/test_kernels.py idiom) and a
 subprocess smoke test proving the fused path imports and runs under
-JAX_PLATFORMS=cpu through the repro.compat shims (satellite 6).
+JAX_PLATFORMS=cpu.
 """
 import os
 import subprocess
@@ -264,6 +264,58 @@ def test_wr_gather_ops_match_ref(m):
     np.testing.assert_allclose(got, want)
 
 
+@pytest.mark.parametrize("m", [1, 3, 5])
+def test_wr_gather_page_region_matches_ref(m):
+    """Whole page records of a multi-dim region, gathered by row index,
+    equal the flat-element reference gather."""
+    jnp = pytest.importorskip("jax.numpy")
+    from repro.kernels.wr_scatter import ops, ref
+    rng = np.random.default_rng(200 + m)
+    region = rng.standard_normal((9, 3, 4, 2, 8)).astype(np.float32)
+    length = 3 * 4 * 2 * 8
+    offs = rng.choice(9, size=m, replace=False)
+    got = np.asarray(ops.gather_records(jnp.asarray(region), offs,
+                                        length))[:m]
+    idx = offs[:, None] * length + np.arange(length)
+    want = np.asarray(ref.reference_gather(jnp.asarray(region),
+                                           idx.astype(np.int32)))
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("shape", [(12, 3, 4, 2, 8), (12, 4, 16)])
+def test_wr_scatter_kernel_page_records(shape):
+    """The Pallas kernel (interpret mode) lands whole multi-dim records,
+    one block of their own trailing dims each, like the reference."""
+    jnp = pytest.importorskip("jax.numpy")
+    from repro.kernels.wr_scatter import ref
+    from repro.kernels.wr_scatter.wr_scatter import wr_scatter
+    rng = np.random.default_rng(len(shape))
+    region = rng.standard_normal(shape).astype(np.float32)
+    offs = np.array([7, 0, 11], np.int32)
+    vals = rng.standard_normal((3,) + shape[1:]).astype(np.float32)
+    got = wr_scatter(jnp.asarray(region), jnp.asarray(vals), offs,
+                     interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(ref.reference(jnp.asarray(region),
+                                                  vals, offs)))
+
+
+def test_wr_scatter_route_is_a_fixed_rule(monkeypatch):
+    """On TPU, records of >= 2 dims take the Pallas kernel and 1-D
+    records the XLA scatter; off TPU everything takes XLA — decided by
+    backend and record rank, never by a failure at run time."""
+    from repro.kernels.wr_scatter import ops
+    monkeypatch.setattr(ops, "_ON_TPU", True)
+    assert ops._use_pallas(np.zeros((4, 2, 8)))
+    assert not ops._use_pallas(np.zeros((4, 16)))
+    monkeypatch.setattr(ops, "_ON_TPU", False)
+    assert not ops._use_pallas(np.zeros((4, 2, 8)))
+    with pytest.raises(ValueError):
+        from repro.kernels.wr_scatter.wr_scatter import wr_scatter
+        wr_scatter(np.zeros((4, 16)), np.zeros((1, 16)), [0],
+                   interpret=True)
+
+
 def test_desc_ring_ops_roundtrip_across_laps():
     """Kernel-level: produced descriptor batches come back bit-exact and
     in order through multiple wraparound laps of the device slots."""
@@ -282,11 +334,11 @@ def test_desc_ring_ops_roundtrip_across_laps():
     assert head == tail == 12
 
 
-# -- compat shims under a pinned CPU backend (satellite 6) -------------------
+# -- the fused path under a pinned CPU backend ------------------------------
 @pytest.mark.slow
 def test_fused_path_runs_under_cpu_subprocess():
     """Fresh interpreter, JAX_PLATFORMS=cpu: the fused WRITE path must
-    import through repro.compat, run one launch per flush, and land the
+    import, run one launch per flush, and land the
     right bytes — proof the jit entry points don't depend on ambient
     backend state from this process."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

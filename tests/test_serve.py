@@ -36,7 +36,7 @@ def test_paged_pool_roundtrip_with_kernel():
     alloc = pool.allocate(n_tokens=16)
     kv = jnp.asarray(np.random.default_rng(1)
                      .standard_normal((16, 2, 8)).astype(np.float32))
-    pool.ingest(alloc, kv, use_kernel=True)      # Pallas interpret path
+    pool.ingest(alloc, kv, use_kernel=True, interpret=True)
     out = pool.gather(alloc, 16)
     np.testing.assert_allclose(np.asarray(out), np.asarray(kv))
 
@@ -113,7 +113,8 @@ def test_pd_disagg_with_ingest_kernel():
     server = PDServer(model, params, max_seq=32, page_tokens=8)
     prompts = np.asarray([[4, 8, 15]], np.int32)
     t1, _ = server.serve(prompts, n_steps=3)
-    t2, _ = server.serve(prompts, n_steps=3, use_kernel=True)
+    t2, _ = server.serve(prompts, n_steps=3, use_kernel=True,
+                         interpret=True)
     np.testing.assert_array_equal(t1, t2)
 
 

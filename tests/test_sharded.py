@@ -243,7 +243,6 @@ def test_reduced_train_step_lowers_on_mesh(arch):
                 optim.opt_state_specs(specs, opt_cfg), "float32")
             step = make_train_step(model, cfg, opt_cfg)
             compiled = jax.jit(step).lower(params, opt, dict(ins)).compile()
-            from repro.compat import cost_analysis
-            assert cost_analysis(compiled).get("flops", 0) > 0
+            assert compiled.cost_analysis().get("flops", 0) > 0
         print("OK")
     """)

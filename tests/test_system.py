@@ -85,8 +85,7 @@ def test_hlo_cost_parser_calibration():
     expected = 2 * B * D * D * L
     np.testing.assert_allclose(res["flops"], expected, rtol=0.05)
     # raw cost_analysis undercounts by ~L (the blind spot we fix)
-    from repro.compat import cost_analysis
-    raw = cost_analysis(compiled).get("flops", 0.0)
+    raw = compiled.cost_analysis().get("flops", 0.0)
     assert raw < 0.5 * expected
 
 
@@ -99,7 +98,7 @@ def test_hlo_cost_collectives_in_scan():
         os.environ["JAX_PLATFORMS"] = "cpu"
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.launch.mesh import make_mesh
         from repro.utils import hlo_cost
 
