@@ -97,7 +97,8 @@ def _run_cluster(model, params, n, faults=None):
     # desc-fetch DMAs summed over every LIVE QP of the fabric (a killed
     # pod's QPs leave fabric.qps; the failover row doesn't use this)
     dmas = sum(qp.desc_fetch_dmas for qp in fabric.qps.values()) - d0
-    compiles = max(p.prefill_compiles for p in pods)
+    # each pod's own prefill jit: its cache holds one entry per compile
+    compiles = max(p._prefill._cache_size() for p in pods)
     migrated = sum(p.kv.pages_migrated for p in pods)
     out = [res[r] for r in rids]
     tele = dict(dmas=dmas, compiles=compiles, migrated=migrated,

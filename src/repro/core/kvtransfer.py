@@ -24,7 +24,7 @@ import numpy as np
 from repro import verbs
 from repro.core.descriptors import TransferPlan
 from repro.core import tx_engine
-from repro.obs import metrics
+from repro.obs import metrics, trace
 
 
 @dataclass
@@ -185,6 +185,8 @@ class KVTransferEngine:
         stacked scatter per leaf region at the peer context flush."""
         if self._peer_lost:
             return False
+        tr = trace.TRACER
+        t0 = tr.now() if tr is not None and tr.detail else None
         wrs = []
         for mr, src_ids, rkey, dst_ids in runs:
             src_ids = np.asarray(src_ids, np.int64).ravel()
@@ -197,6 +199,8 @@ class KVTransferEngine:
                     remote_key=int(rkey),
                     remote_offsets=np.asarray([t], np.int64),
                     signaled=True))
+        if t0 is not None:
+            tr.complete("wr_build", t0, wrs=len(wrs))
         try:
             self.ep.post_send(wrs)
             self.ep.flush()

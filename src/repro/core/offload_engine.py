@@ -28,6 +28,7 @@ import numpy as np
 
 from repro.core.descriptors import (OP_BATCH_READ, OP_LIST_TRAVERSAL)
 from repro.kernels.wr_scatter import ops as wr_scatter_ops
+from repro.obs import trace
 
 
 def dedupe_last_wins(offs: np.ndarray, vals):
@@ -107,6 +108,8 @@ class QPContext:
         DONATES the outgoing region buffer). The oracle
         (`coalesce_writes=False`) keeps eager per-op `at[].set`/`take`
         calls — it never compiles, by contract."""
+        tr = trace.TRACER
+        t0 = tr.now() if tr is not None and tr.detail else None
         pending = [(i, d) for i, d in enumerate(
             self._dma_queue[self._scan_from:], start=self._scan_from)
             if i not in self._dma_done]
@@ -205,6 +208,12 @@ class QPContext:
         # advance only once everything retired: a mid-flush error leaves
         # the survivors rescannable by the next flush instead of orphaned
         self._scan_from = len(self._dma_queue)
+        if t0 is not None and pending:
+            writes = [d for _, d in pending if d.op == "WRITE"]
+            tr.complete("dma_flush", t0, reads=len(pending) - len(writes),
+                        writes=len(writes),
+                        bytes=sum(int(getattr(d.buf, "nbytes", 0))
+                                  for d in writes))
 
     def submit_resp(self, buf):
         self.resp = buf

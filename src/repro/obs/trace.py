@@ -12,6 +12,18 @@ never allocates unboundedly, old events fall off the back — and
 exportable as Chrome ``trace_event`` JSON that loads directly in
 perfetto (ui.perfetto.dev) or chrome://tracing.
 
+A tracer built with ``detail=True`` also records the phases of moving
+records under a verbs call (``wr_build``, ``mr_fetch``, ``dma_flush``)
+and the serving cluster's request lifecycle (``serve.submit`` ->
+``serve.admit`` -> ``serve.prefill`` -> ``serve.page_fill`` ->
+``serve.migrate`` -> ``serve.activate`` -> ``serve.activated``, each
+event carrying ``rid``) and decode steps (``serve.decode_step``).
+Without it a trace is the stage chain alone, so whatever unions its
+spans reads what it always read. `anchor` marks one instant on the ring's clock
+and on the JAX profiler's timeline; `clock_map` turns two such anchors
+into the map from ring time to profile time, so the program's spans
+can be laid over the device trace.
+
 The disabled case is the default and costs nothing on the hot loop:
 ``TRACER`` is a module global that instrumentation sites read once per
 *batch operation* (a chain post, a dispatch run, a CQ publish — never
@@ -29,6 +41,7 @@ Usage:
 from __future__ import annotations
 
 import json
+import sys
 import time
 from contextlib import contextmanager
 
@@ -36,17 +49,24 @@ from contextlib import contextmanager
 #: instrumentation sites guard with ``if trace.TRACER is not None``.
 TRACER = None
 
+#: Name of the anchor event, in the ring and on the profiler's timeline.
+ANCHOR = "obs.anchor"
+
 
 class Tracer:
     """Fixed-ring span/event recorder. `clock` is injectable (tests pin
-    a deterministic clock for the golden export)."""
+    a deterministic clock for the golden export). `detail` turns on the
+    transfer-phase and serving spans; their sites test ``tr.detail``
+    after the None check."""
 
-    def __init__(self, capacity: int = 65536, clock=time.perf_counter_ns):
+    def __init__(self, capacity: int = 65536, clock=time.perf_counter_ns,
+                 detail: bool = False):
         assert capacity > 0
         self.capacity = capacity
         self._clock = clock
         self._events: list = [None] * capacity
         self._n = 0                 # monotonic event count
+        self.detail = detail
 
     # -- recording (the hot side) -------------------------------------------
     def now(self) -> int:
@@ -65,6 +85,28 @@ class Tracer:
         self._events[self._n % self.capacity] = \
             ("i", name, self._clock(), 0, tid, args)
         self._n += 1
+
+    def anchor(self) -> None:
+        """Mark this instant on both clocks: read the ring clock, enter
+        and leave a ``jax.profiler.TraceAnnotation`` named `ANCHOR`,
+        read the clock again, and record both readings as an `ANCHOR`
+        span. Call it right after the tracer is installed and right
+        before it is removed while a profiler runs; `clock_map` pairs
+        the ring's anchors with the profile's. jax is taken from
+        ``sys.modules`` only (this module never imports it): without
+        it, only the ring side is recorded."""
+        jax = sys.modules.get("jax")
+        t0 = self._clock()
+        if jax is not None:
+            with jax.profiler.TraceAnnotation(ANCHOR):
+                pass
+        self.complete(ANCHOR, t0, tid="obs")
+
+    def anchors(self) -> list[tuple[int, int]]:
+        """The retained anchors' (start, end) ring readings, oldest
+        first."""
+        return [(e[2], e[2] + e[3]) for e in self.events()
+                if e[1] == ANCHOR]
 
     # -- introspection ------------------------------------------------------
     def __len__(self) -> int:
@@ -116,6 +158,22 @@ class Tracer:
         return path
 
 
+def clock_map(ring: list, profile: list):
+    """Map ring time onto a profile's timeline from the same anchors
+    seen on both sides: `ring` holds (start, end) ring readings
+    (`Tracer.anchors`), `profile` the (start, end) of each `ANCHOR`
+    annotation on the profile, in the same order. Midpoints are
+    paired; the first and the last give the offset and the drift (one
+    anchor: the offset alone). Returns ``f(ring_t) -> profile_t``."""
+    if not ring or len(ring) != len(profile):
+        raise ValueError(f"{len(ring)} ring anchors, {len(profile)} on "
+                         "the profile")
+    r0, r1 = (sum(ring[0]) / 2, sum(ring[-1]) / 2)
+    p0, p1 = (sum(profile[0]) / 2, sum(profile[-1]) / 2)
+    scale = (p1 - p0) / (r1 - r0) if r1 != r0 else 1.0
+    return lambda t: p0 + (t - r0) * scale
+
+
 def install(tracer: Tracer | None = None) -> Tracer:
     """Enable tracing (idempotent: an explicit tracer replaces the
     current one). Returns the installed tracer."""
@@ -133,11 +191,12 @@ def uninstall() -> Tracer | None:
 
 
 @contextmanager
-def tracing(capacity: int = 65536, clock=time.perf_counter_ns):
+def tracing(capacity: int = 65536, clock=time.perf_counter_ns,
+            detail: bool = False):
     """Scoped enable: ``with trace.tracing() as t: ...; t.save(path)``.
     Always uninstalls, so an exception can't leave the datapath paying
     for tracing nobody reads."""
-    t = install(Tracer(capacity, clock))
+    t = install(Tracer(capacity, clock, detail))
     try:
         yield t
     finally:

@@ -38,7 +38,7 @@ import numpy as np
 from repro import verbs
 from repro.core.descriptors import (make_descriptor, OP_KV_ACTIVATE,
                                     OP_KV_WRITE)
-from repro.obs import metrics
+from repro.obs import metrics, trace
 from repro.serve.kvcache import pad_caches
 from repro.serve.paged import (PagePool, bucket_len, bucketable,
                                make_paged_step, pageable)
@@ -242,6 +242,9 @@ class ServeEngine:
         self.pool.bind_slot(slot, ids)
         self.positions[slot] = plen - 1
         self.slots[slot] = rid
+        tr = trace.TRACER
+        if tr is not None and tr.detail:
+            tr.instant("serve.activated", tid=self.gid, rid=rid, slot=slot)
 
     # -- engine side ----------------------------------------------------
     def _free_slot(self) -> int | None:
@@ -333,6 +336,8 @@ class ServeEngine:
         for i in active:
             tokens[i, 0] = self.requests[self.slots[i]].out_tokens[-1]
         pos = jnp.asarray(self.positions + 1)               # write index
+        tr = trace.TRACER
+        t0 = tr.now() if tr is not None and tr.detail else None
         if self.paged:
             # table-indirected decode: ONE jitted launch gathers pages,
             # steps, and scatters the updated pages back; RDMA-migrated
@@ -345,6 +350,9 @@ class ServeEngine:
             logits, self.caches = self._decode(
                 self.params, jnp.asarray(tokens), self.caches, pos)
         nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+        if t0 is not None:
+            tr.complete("serve.decode_step", t0, tid=self.gid,
+                        active=len(active))
         for i in active:
             rid = self.slots[i]
             req = self.requests[rid]

@@ -252,4 +252,6 @@ def make_paged_step(model, pool: PagePool):
                 r.reshape((B * ppslot,) + pg.shape[1:])))
         return logits, outs
 
+    # compiles as `jit_step`: the benchmark's decode readers find the
+    # paged step's device time under that program name
     return jax.jit(step)

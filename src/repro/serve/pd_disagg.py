@@ -18,7 +18,7 @@ from repro import verbs
 from repro.core.descriptors import (make_descriptor, OP_KV_ACTIVATE,
                                     TransferPlan)
 from repro.core.kvtransfer import KVTransferEngine
-from repro.obs import metrics
+from repro.obs import metrics, trace
 from repro.serve.kvcache import PagedKVPool, pad_caches
 from repro.serve.paged import PagePool, bucket_len, bucketable, pageable
 
@@ -147,7 +147,6 @@ class PrefillPod:
     verbs traffic.
     """
 
-    prefill_compiles = metrics.counter_attr()
     requests_processed = metrics.counter_attr()
 
     def __init__(self, model, params, *, fabric, gid: str,
@@ -155,7 +154,6 @@ class PrefillPod:
                  page_tokens: int = 16):
         metrics.instance_scope(self, "prefillpod", indexed=True)
         assert pageable(model), "PrefillPod needs a pageable cache"
-        self.prefill_compiles = 0
         self.requests_processed = 0
         self.model = model
         self.fabric = fabric
@@ -172,8 +170,9 @@ class PrefillPod:
                              device=self.device)
         self.kv = KVTransferEngine(model, 1, max_seq, fabric=fabric,
                                    src_gid=gid, decode_gids=decode_gids)
+        # compiles as `jit_prefill`: the benchmark's device-trace
+        # readers find prefill time under that program name
         self._prefill = jax.jit(model.prefill)
-        self._seen_lens: set[int] = set()
         # per-decode-gid activation endpoints (to the ENGINE listeners,
         # not the kv transfer listeners): gid -> (ep, lost-flag box)
         self._act_eps: dict[str, tuple] = {}
@@ -189,11 +188,8 @@ class PrefillPod:
 
     def _run_prefill(self, prompt: np.ndarray):
         plen = int(prompt.size)
-        pad = bucket_len(plen, self.max_seq) if self.bucketed else plen
-        if pad not in self._seen_lens:
-            self._seen_lens.add(pad)
-            self.prefill_compiles += 1
         if self.bucketed:
+            pad = bucket_len(plen, self.max_seq)
             padded = np.zeros((1, pad), np.int32)
             padded[0, :plen] = prompt
             return self._prefill(self.params, jnp.asarray(padded),
@@ -249,10 +245,22 @@ class PrefillPod:
         (the survivor, if the chosen pod died mid-flight)."""
         prompt = np.asarray(prompt, np.int32).ravel()
         plen = int(prompt.size)
+        tr = trace.TRACER
+        if tr is not None and not tr.detail:
+            tr = None
+        t0 = tr.now() if tr is not None else 0
         logits, caches = self._run_prefill(prompt)
         first_tok = int(jnp.argmax(logits[0, -1]))
+        if tr is not None:
+            tr.complete("serve.prefill", t0, tid=self.gid, rid=rid,
+                        tokens=plen, bucket=bucket_len(plen, self.max_seq)
+                        if self.bucketed else plen)
+            t0 = tr.now()
         src_ids = self.pool.alloc(self.pool.pages_for(plen))
         self.pool.fill(src_ids, caches)
+        if tr is not None:
+            tr.complete("serve.page_fill", t0, tid=self.gid, rid=rid,
+                        pages=len(src_ids))
         if decode_gid is not None:
             self.kv.retarget(decode_gid)
 
@@ -262,18 +270,32 @@ class PrefillPod:
             return [(mr, src_ids, rkey, dst_ids)
                     for mr, (rkey, dst_ids) in zip(self.pool.mrs, lease)]
 
-        try:
-            runs = reserve_on(self.kv.decode_gid)
+        def migrate(runs):
+            t0 = tr.now() if tr is not None else 0
             landed = self.kv.migrate_pages(runs, retarget=reserve_on)
+            if tr is not None:
+                tr.complete("serve.migrate", t0, tid=self.gid, rid=rid,
+                            pages=len(src_ids))
+            return landed
+
+        def activate(gid):
+            t0 = tr.now() if tr is not None else 0
+            ok = self._activate_once(engines[gid], rid, plen)
+            if tr is not None:
+                tr.complete("serve.activate", t0, tid=self.gid, rid=rid,
+                            decode=gid)
+            return ok
+
+        try:
+            landed = migrate(reserve_on(self.kv.decode_gid))
             for _ in range(self.kv.replay_limit + 1):
-                if self._activate_once(engines[landed], rid, plen):
+                if activate(landed):
                     break
                 # pod died between migrate and activation: same replay
                 # as a mid-migrate death — survivor re-reserves, pages
                 # re-migrate, activation re-sends
                 self.kv._failover()
-                runs = reserve_on(self.kv.decode_gid)
-                landed = self.kv.migrate_pages(runs, retarget=reserve_on)
+                landed = migrate(reserve_on(self.kv.decode_gid))
             else:
                 raise verbs.QPStateError(
                     f"request {rid}: activation failed after "

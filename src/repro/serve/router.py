@@ -22,7 +22,7 @@ from __future__ import annotations
 
 from collections import deque
 
-from repro.obs import metrics
+from repro.obs import metrics, trace
 
 
 class Router:
@@ -92,6 +92,9 @@ class Router:
         self.requests_routed += 1
         self._placement[rid] = (list(prompt), max_new_tokens)
         self._queue.append(rid)
+        tr = trace.TRACER
+        if tr is not None and tr.detail:
+            tr.instant("serve.submit", tid="router", rid=rid)
         return rid
 
     def _dispatch(self):
@@ -104,6 +107,9 @@ class Router:
             if gid is None:
                 return                      # full — retry next iteration
             self._queue.popleft()
+            tr = trace.TRACER
+            if tr is not None and tr.detail:
+                tr.instant("serve.admit", tid="router", rid=rid, decode=gid)
             pod = self.prefill_pods[self._rr % len(self.prefill_pods)]
             self._rr += 1
             self._owner[rid] = pod.process(rid, prompt, max_new,

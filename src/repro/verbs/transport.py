@@ -430,6 +430,9 @@ class LoopbackTransport:
         if fusable < 2:
             return None
         rows = None
+        tr = trace.TRACER
+        if tr is not None and not tr.detail:
+            tr = None
         i = 0
         while i < n:
             mr = mrs[i]
@@ -443,9 +446,13 @@ class LoopbackTransport:
                 cat = np.concatenate(seg)
                 # ONE region fetch + ONE fused gather launch + ONE host
                 # conversion for the whole segment
+                t0 = tr.now() if tr is not None else 0
                 block = wr_scatter_ops.gather_records(
                     qp.pd.mr_array(mr), cat, int(mr.record))
                 host = np.asarray(block[:cat.size])
+                if tr is not None:
+                    tr.complete("mr_fetch", t0, records=int(cat.size),
+                                bytes=int(host.nbytes))
                 rec_shape = tuple(mr.shape[1:])
                 p = 0
                 for k, off in zip(range(i, j), seg):
