@@ -79,9 +79,9 @@ class QPContext:
         """Queue one DMA. WRITEs carry their source data in `buf`
         (record rows matching `offsets`); READs leave it None. A
         mutable host buffer is SNAPSHOTTED at submission (the caller
-        may reuse it — Table-2 handlers loop over scratch); a device
-        array is immutable, so it stages as-is and the one device
-        conversion happens at the fused scatter, not per submission."""
+        may reuse it — Table-2 handlers loop over scratch) and converts
+        to the device at the fused scatter; a device array is
+        immutable, so it stages as-is and lands without a host copy."""
         dma_id = len(self._dma_queue)
         if buf is not None and not isinstance(buf, jnp.ndarray):
             buf = np.array(buf)
@@ -105,7 +105,10 @@ class QPContext:
 
         The coalescing path launches through the fused jitted ops
         (`kernels/wr_scatter/ops`, counted as `fused/launches`; scatter
-        DONATES the outgoing region buffer). The oracle
+        DONATES the outgoing region buffer). Host-buffer WRITEs of a run
+        stack on the host into one scatter; a run holding a device
+        buffer lands op by op in submission order, so no device buffer
+        is ever copied to the host to be stacked. The oracle
         (`coalesce_writes=False`) keeps eager per-op `at[].set`/`take`
         calls — it never compiles, by contract."""
         tr = trace.TRACER
@@ -156,6 +159,13 @@ class QPContext:
                     return
                 if len(writes) == 1:
                     scatter_one(*writes[0])
+                    writes.clear()
+                    return
+                if any(isinstance(d.buf, jnp.ndarray) for _, d in writes):
+                    # device buffers land where they are: one scatter per
+                    # op, in submission order (last writer wins)
+                    for i, d in writes:
+                        scatter_one(i, d)
                     writes.clear()
                     return
                 arr = self.engine.regions[region]
@@ -214,6 +224,11 @@ class QPContext:
                         writes=len(writes),
                         bytes=sum(int(getattr(d.buf, "nbytes", 0))
                                   for d in writes))
+        # a retired WRITE's payload has landed: the queue, which lives as
+        # long as the QP, must not keep it (a device block would pin its
+        # HBM for every migration the QP ever carried)
+        for _, d in pending:
+            d.buf = None
 
     def submit_resp(self, buf):
         self.resp = buf

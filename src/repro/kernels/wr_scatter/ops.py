@@ -60,10 +60,16 @@ def _scatter(region, vals, offs, *, use_pallas=False):
 @partial(jax.jit, static_argnames=("length",))
 def _gather(region, offs, *, length):
     # whole records by row index: no flat element index, so a region of
-    # more than 2**31 elements cannot wrap an int32 index
-    if math.prod(region.shape[1:]) != length:
-        region = region.reshape(-1, length)
-    return jnp.take(region, offs, axis=0).reshape(offs.shape[0], length)
+    # more than 2**31 elements cannot wrap an int32 index. `length` is an
+    # element count (flat rows) or a record shape (rows in that shape):
+    # the reshape lives in this program, never in an eager op after it
+    rec = tuple(length) if isinstance(length, tuple) else (length,)
+    if math.prod(region.shape[1:]) != math.prod(rec):
+        region = region.reshape((-1,) + rec)
+    rows = jnp.take(region, offs, axis=0)
+    if rows.shape[1:] != rec:
+        rows = rows.reshape((offs.shape[0],) + rec)
+    return rows
 
 
 _ON_TPU: bool | None = None
@@ -86,14 +92,16 @@ def _bucket(m: int) -> int:
 
 def scatter_records(region, offs, vals):
     """ONE fused, donated scatter: region[offs[i]] <- vals[i] rows.
-    offs is 1-D with vals row-aligned (`dedupe_last_wins` upstream);
-    the flush's single host->device conversion happens at this call."""
+    offs is 1-D with vals row-aligned (`dedupe_last_wins` upstream). A
+    host `vals` converts to the device at this call; a device `vals`
+    lands as it is."""
     offs = np.asarray(offs, np.int32).ravel()
     m = offs.size
     b = _bucket(m)
     if b != m and isinstance(vals, np.ndarray):
-        # device-array sources skip bucketing (their shapes come from
-        # handler code, not ragged WR runs — padding one would sync)
+        # device-array sources skip bucketing: padding one would be an
+        # eager device op (the transport's MR-sourced WRITE blocks arrive
+        # already bucketed by `gather_records`, offsets padded to match)
         offs = np.concatenate([offs, np.repeat(offs[-1:], b - m)])
         vals = np.concatenate([vals, np.repeat(vals[-1:], b - m, axis=0)])
     _count()
@@ -113,9 +121,10 @@ def scatter_one(region, offsets, buf):
     return _scatter(region, buf, offsets, use_pallas=False)
 
 
-def gather_records(region, offs, length: int):
-    """ONE fused gather of `length`-element records at record offsets
-    `offs`: returns a (padded_n, length) block — callers slice the true
+def gather_records(region, offs, length):
+    """ONE fused gather of records at record offsets `offs`. `length` is
+    the record's element count, giving a (padded_n, length) block, or
+    its shape, giving a (padded_n, *shape) block. Callers slice the true
     prefix rows (the pad tail re-reads the last record)."""
     offs = np.asarray(offs, np.int32).ravel()
     n = offs.size
@@ -123,7 +132,9 @@ def gather_records(region, offs, length: int):
     if b != n:
         offs = np.concatenate([offs, np.repeat(offs[-1:], b - n)])
     _count()
-    return _gather(region, offs, length=int(length))
+    length = tuple(int(d) for d in length) if isinstance(
+        length, (tuple, list)) else int(length)
+    return _gather(region, offs, length=length)
 
 
 reference = ref.reference

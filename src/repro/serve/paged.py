@@ -8,8 +8,8 @@ protection domain. Cache state IS engine DMA memory:
 
   * one-sided RDMA_WRITEs from a prefill pod land pages directly in the
     pool (``KVTransferEngine.migrate_pages`` — the record unit of the MR
-    is exactly one page, so a run of page writes rides the fused
-    `_fused_mr_rows` gather + one stacked scatter per leaf);
+    is exactly one page, so a leaf's run of page writes is one fused
+    gather whose device block lands in one scatter, never on the host);
   * the decode step reads pages through a slot -> page-table
     indirection (`make_paged_step`): gather pages into the dense
     layout, run `model.decode_step`, scatter the updated pages back —

@@ -11,12 +11,15 @@ One `process()` pass is the unit of batching. Dispatch is BATCH-WISE
 (FlexTOE's discipline): consecutive same-opcode WRs form a *run*, and a
 run costs O(1) python/launch overhead —
 
-  * a run of RDMA_WRITEs into one remote MR submits ONE stacked DMA;
+  * a run of RDMA_WRITEs into one remote MR submits ONE stacked DMA; a
+    run wholly sourced from one local MR (KV page migration) submits
+    the gathered device block itself, so its payload never leaves the
+    device (`_device_write`);
   * a run of SENDs into an SRQ claims its recv WRs with ONE
     `take_many`;
   * MR-sourced payloads (SEND or WRITE sources with payload=None and
     mr+offsets) extract with ONE fused `gather_records` launch per
-    same-local-MR segment (`_fused_mr_rows`), not a per-WR
+    same-local-MR segment (`_gather_segment`), not a per-WR
     `pd.mr_array` + device index;
   * every RDMA_READ posted in the pass coalesces into one fused gather
     per remote region (`QPContext._flush`);
@@ -33,6 +36,7 @@ from dataclasses import dataclass
 from itertools import islice
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -397,62 +401,73 @@ class LoopbackTransport:
         return wqe.unpack_inline_batch(block[j0:j0 + len(run)], nb, dc)
 
     @staticmethod
-    def _fused_mr_rows(qp, run):
-        """Fused extraction for the MR-sourced WRs of one claimed run:
-        maximal segments of consecutive WRs sourcing from the SAME local
-        MR (payload=None, mr+offsets — the NIC-DMA-reads-the-source
-        contract) gather through ONE `gather_records` launch per segment
-        and ONE host conversion, instead of a per-WR `pd.mr_array` +
-        device index each. Returns a run-aligned list whose fused
-        positions hold the (k, *rec) numpy row blocks (bit-exact with
-        the oracle's per-WR gather — same region, same offsets, no
-        region mutation can interleave because every DMA of the pass
-        queues until settle) and None elsewhere; or None when nothing
-        fuses. A WR whose offsets don't normalize stays un-fused so it
-        fails on the per-WR path at exactly the oracle's position."""
-        n = len(run)
-        mrs: list = [None] * n
-        offs: list = [None] * n
-        fusable = 0
-        for i, ps in enumerate(run):
-            wr = ps.wr
-            if ps.inline_row is not None or ps.inline_src is not None \
-                    or wr.payload is not None or wr.mr is None:
-                continue
-            try:
-                off = np.asarray(wr.offsets, np.int64).ravel()
-            except Exception:
-                continue
-            if off.size:
-                mrs[i] = wr.mr
-                offs[i] = off
-                fusable += 1
-        if fusable < 2:
+    def _mr_source(ps):
+        """The local MR and normalized source offsets of an MR-sourced WR
+        (payload=None, mr+offsets — the NIC-DMA-reads-the-source
+        contract), or None: inline, by-value and malformed or empty
+        offsets stay on the per-WR path, so they fail at exactly the
+        oracle's position."""
+        wr = ps.wr
+        if ps.inline_row is not None or ps.inline_src is not None \
+                or wr.payload is not None or wr.mr is None:
             return None
-        rows = None
+        try:
+            off = np.asarray(wr.offsets, np.int64).ravel()
+        except Exception:
+            return None
+        return (wr.mr, off) if off.size else None
+
+    @staticmethod
+    def _gather_segment(qp, mr, seg, host: bool):
+        """ONE region fetch + ONE fused `gather_records` launch for the
+        source offsets `seg` (one array per WR) of one local MR: a
+        bucketed ``(padded_n, *record)`` block whose first ``n`` rows are
+        the sources in WR order (bit-exact with the oracle's per-WR
+        gather — same region, same offsets, and no region mutation can
+        interleave because every DMA of the pass queues until settle).
+        ``host`` copies the true rows to the host (the SEND path and the
+        host WRITE path); otherwise the block stays a device array."""
         tr = trace.TRACER
         if tr is not None and not tr.detail:
             tr = None
+        t0 = tr.now() if tr is not None else 0
+        cat = np.concatenate(seg)
+        block = wr_scatter_ops.gather_records(
+            qp.pd.mr_array(mr), cat, tuple(mr.shape[1:]))
+        if host:
+            block = np.asarray(block[:cat.size])
+        if tr is not None:
+            tr.complete("mr_fetch", t0, records=int(cat.size),
+                        bytes=int(cat.size) * int(mr.record)
+                        * np.dtype(mr.dtype).itemsize)
+        return block, int(cat.size)
+
+    @classmethod
+    def _fused_mr_rows(cls, qp, run):
+        """Fused host extraction for the MR-sourced WRs of one claimed
+        run: maximal segments of consecutive WRs sourcing from the SAME
+        local MR gather through ONE launch per segment
+        (`_gather_segment`) and one copy to the host, instead of a per-WR
+        `pd.mr_array` + device index each. Returns a run-aligned list
+        whose fused positions hold the (k, *rec) numpy row blocks and
+        None elsewhere; or None when nothing fuses."""
+        n = len(run)
+        srcs = [cls._mr_source(ps) for ps in run]
+        if sum(s is not None for s in srcs) < 2:
+            return None
+        rows = None
         i = 0
         while i < n:
-            mr = mrs[i]
+            mr = srcs[i][0] if srcs[i] is not None else None
             j = i + 1
-            while mr is not None and j < n and mrs[j] is mr:
+            while mr is not None and j < n and srcs[j] is not None \
+                    and srcs[j][0] is mr:
                 j += 1
             if mr is not None and j - i >= 2:
                 if rows is None:
                     rows = [None] * n
-                seg = offs[i:j]
-                cat = np.concatenate(seg)
-                # ONE region fetch + ONE fused gather launch + ONE host
-                # conversion for the whole segment
-                t0 = tr.now() if tr is not None else 0
-                block = wr_scatter_ops.gather_records(
-                    qp.pd.mr_array(mr), cat, int(mr.record))
-                host = np.asarray(block[:cat.size])
-                if tr is not None:
-                    tr.complete("mr_fetch", t0, records=int(cat.size),
-                                bytes=int(host.nbytes))
+                seg = [off for _, off in srcs[i:j]]
+                host, _ = cls._gather_segment(qp, mr, seg, host=True)
                 rec_shape = tuple(mr.shape[1:])
                 p = 0
                 for k, off in zip(range(i, j), seg):
@@ -581,7 +596,7 @@ class LoopbackTransport:
                     # ALL landing validation happens here in the fallible
                     # phase — offsets normalized, payload reshaped
                     # (`_as_records` so a bad payload fails exactly like
-                    # the oracle's), numpy staging for the stack (the ONE
+                    # the oracle's), numpy staging for the stack (the
                     # device conversion happens at the fused scatter)
                     off = np.asarray(rwr.offsets).ravel()
                     buf = np.asarray(self._as_records(rwr.mr, payload))
@@ -700,10 +715,66 @@ class LoopbackTransport:
                 drain_pending()
         flush_stack()
 
+    def _device_write(self, qp, peer, mr, sub, stage, touch) -> bool:
+        """Land a WRITE sub-run device to device when what it carries
+        allows it: every WR sources from ONE local MR with the remote
+        MR's record shape, each WR's source rows match its destination
+        offsets (no broadcasting WR), and no destination offset repeats
+        (last-writer-wins would need `dedupe_last_wins`). Then the
+        segment's gathered device block is the DMA's `buf`: ONE
+        `gather_records` launch, ONE `submit_dma` with the destination
+        offsets padded to the block's bucketed length by repeating the
+        last one (so every pad slot writes the identical last record),
+        and no payload byte crosses to the host. Returns False, having
+        done nothing, when the sub-run must take the host path."""
+        src = None
+        seg, dsts = [], []
+        for ps in sub:
+            got = self._mr_source(ps)
+            if got is None or (src is not None and got[0] is not src):
+                return False
+            src = got[0]
+            try:
+                dst = np.asarray(ps.wr.remote_offsets, np.int32).ravel()
+            except (TypeError, ValueError, OverflowError):
+                return False            # the host path raises it
+            if dst.size != got[1].size:
+                return False
+            seg.append(got[1])
+            dsts.append(dst)
+        if tuple(src.shape[1:]) != tuple(mr.shape[1:]):
+            return False
+        offs = np.concatenate(dsts)
+        if np.unique(offs).size != offs.size:
+            return False
+        block, n = self._gather_segment(qp, src, seg, host=False)
+        if block.shape[0] != n:
+            offs = np.concatenate(
+                [offs, np.repeat(offs[-1:], block.shape[0] - n)])
+        region = peer.ctx.engine.regions[mr.name]
+        if block.devices() != region.devices():
+            # a physically backed grid (`Fabric.device_of`): one device
+            # to device copy onto the destination region's chip
+            block = jax.device_put(block, region.sharding)
+        peer.ctx.submit_dma("WRITE", mr.name, offs, mr.record, buf=block)
+        touch(peer.ctx)
+        for ps, dst in zip(sub, dsts):
+            if ps.wr.signaled:
+                stage(qp.send_cq, ps.wr.opcode, ps.wr.wr_id,
+                      wqe.IBV_WC_SUCCESS, int(dst.size))
+        fused = metrics.get_registry().scope("fused")
+        fused.counter("device_write_runs").inc()
+        fused.counter("device_write_bytes").inc(
+            n * int(mr.record) * np.dtype(mr.dtype).itemsize)
+        return True
+
     def _run_writes(self, qp, peer, run, stage, touch) -> int:
         """Consecutive WRITEs to one remote MR fuse into ONE stacked
         `submit_dma` (offsets concatenated, record rows stacked) — one
-        DmaOp, one scatter launch, N completions.
+        DmaOp, one scatter launch, N completions. A sub-run wholly
+        sourced from one local MR lands its gathered device block
+        directly (`_device_write`, counted in `fused/device_write_runs`);
+        every other sub-run stacks host rows (`fused/host_write_runs`).
 
         Each sub-run is all-or-nothing: every source is gathered and
         reshaped BEFORE anything is submitted or any SUCCESS CQE is
@@ -728,10 +799,15 @@ class LoopbackTransport:
                               wqe.IBV_WC_ACCESS_ERR, 0)
                     done += len(sub)
                     continue
-                # fallible phase: gather every source up front.
-                # numpy-first: a variadic device concatenate over
+                if self._device_write(qp, peer, mr, sub, stage, touch):
+                    done += len(sub)
+                    continue
+                metrics.get_registry().scope("fused").counter(
+                    "host_write_runs").inc()
+                # host path, fallible phase: gather every source up
+                # front. numpy-first: a variadic device concatenate over
                 # thousands of tiny operands costs more than the scatter
-                # it feeds — the ONE device conversion is submit_dma's.
+                # it feeds — the device conversion is submit_dma's.
                 # MR-sourced WRITEs fuse their source extraction the
                 # same way as SENDs: one gather launch per same-local-MR
                 # segment instead of a per-WR `pd.mr_array` + index.

@@ -16,6 +16,7 @@ except ImportError:                      # offline rig: sampled fallback
 
 from repro import verbs
 from repro.core.notification import DoorbellQueue, Ring
+from repro.obs import metrics
 from repro.verbs import wqe
 
 
@@ -107,7 +108,8 @@ def test_doorbell_queue_empty_batch_is_noop():
 
 # -- dispatch: run-grouped vs element-at-a-time ------------------------------
 _KINDS = ["send_inline", "send_f64", "send_u8", "send_big", "send_unsig",
-          "send_mr", "write", "write_bad", "read"]
+          "send_mr", "write", "write_bad", "read", "write_mr",
+          "write_mr_dup", "write_mr_bcast"]
 
 
 def _run_chain(kinds, n_recv, use_srq, vectorized):
@@ -158,6 +160,21 @@ def _run_chain(kinds, n_recv, use_srq, vectorized):
                 remote_key=0xDEAD if kind == "write_bad" else dst.rkey,
                 remote_offsets=offs,
                 payload=rng.standard_normal((k, 4)).astype(np.float32)))
+        elif kind in ("write_mr", "write_mr_dup", "write_mr_bcast"):
+            # MR-sourced: a distinct-offset WR, one that lands on the
+            # same destination record as every other `_dup`, and one
+            # whose single source row broadcasts over two records
+            k = int(rng.integers(1, 4)) if kind == "write_mr" else 1
+            if kind == "write_mr":
+                dst_offs = rng.choice(8, size=k, replace=False)
+            elif kind == "write_mr_dup":
+                dst_offs = np.asarray([7])
+            else:
+                dst_offs = rng.choice(8, size=2, replace=False)
+            wrs.append(verbs.SendWR(
+                wr_id=i, opcode=verbs.IBV_WR_RDMA_WRITE,
+                remote_key=dst.rkey, remote_offsets=dst_offs, mr=src,
+                offsets=rng.choice(8, size=k, replace=False)))
         elif kind == "read":
             k = int(rng.integers(1, 4))
             wrs.append(verbs.SendWR(
@@ -200,6 +217,33 @@ def test_dispatch_vectorized_bit_exact(kinds, n_recv, use_srq):
     and stalled descriptors match the scalar transport exactly."""
     _assert_same(_run_chain(kinds, n_recv, use_srq, vectorized=True),
                  _run_chain(kinds, n_recv, use_srq, vectorized=False))
+
+
+@pytest.mark.parametrize("kinds,device_runs,host_runs", [
+    # ONE chain: `write_bad` (an unknown rkey) splits the WRITEs into
+    # sub-runs, so the first and last land device to device into one
+    # region in one flush; between them duplicate destination offsets,
+    # a broadcasting WR and a payload-sourced WR each force the host path
+    (["write_mr", "write_bad", "write_mr_dup", "write_mr_dup",
+      "write_bad", "write_mr_bcast", "write_mr", "write_bad", "write_mr",
+      "write", "write_mr", "write_bad", "write_mr"], 2, 3),
+    (["write_mr_dup", "write_mr_dup"], 0, 1),
+    (["write_mr", "write_mr_bcast", "write_mr"], 0, 1),
+    (["write_mr", "write", "write_mr"], 0, 1),
+    (["write_mr", "send_inline", "write_mr", "read", "write_mr"], 3, 0),
+], ids=["one_chain", "dup_dst", "broadcast", "payload_between",
+        "device_ops_around_send_and_read"])
+def test_write_fallbacks_bit_exact(kinds, device_runs, host_runs):
+    """MR-sourced WRITE sub-runs land device to device only where their
+    shape allows; every fallback and every mix of device and host
+    buffers in one flush stays bit-exact with the scalar oracle."""
+    fused = metrics.get_registry().scope("fused")
+    dev0 = fused.counter("device_write_runs").value
+    host0 = fused.counter("host_write_runs").value
+    vec = _run_chain(kinds, 4, False, vectorized=True)
+    assert fused.counter("device_write_runs").value - dev0 == device_runs
+    assert fused.counter("host_write_runs").value - host0 == host_runs
+    _assert_same(vec, _run_chain(kinds, 4, False, vectorized=False))
 
 
 def test_rnr_mid_chain_stalls_identically():

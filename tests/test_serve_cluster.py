@@ -15,6 +15,10 @@ The load-bearing invariants:
     dicts and pages return to the pool.
 """
 import math
+import os
+import subprocess
+import sys
+import textwrap
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +40,7 @@ from repro.serve.pd_disagg import PrefillPod
 from repro.serve.router import Router
 from repro.verbs.ratectl import RateController, RouteState
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DECODE_GIDS = ["pod2/dev0", "pod3/dev0"]
 PREFILL_GIDS = ["pod0/dev0", "pod1/dev0"]
 
@@ -218,6 +223,184 @@ def test_migrate_pages_one_fused_launch_per_leaf_run(gemma):
             np.asarray(dst_r)[np.asarray(lease[i][1])])
     pod.close()
     eng.close()
+
+
+class _NumpyWithoutDeviceArrays:
+    """numpy for the datapath modules under test, refusing any jax array
+    it is asked to convert. `jax.transfer_guard_device_to_host` only
+    fires where a copy really happens, and CPU arrays convert to numpy
+    without one, so on the CPU the tests guard the conversion itself."""
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    @staticmethod
+    def _refuse(x):
+        if isinstance(x, jax.Array):
+            raise AssertionError(
+                f"device array {x.shape} {x.dtype} copied to the host")
+        if isinstance(x, (list, tuple)):
+            for e in x:
+                _NumpyWithoutDeviceArrays._refuse(e)
+
+    def asarray(self, a, *args, **kw):
+        self._refuse(a)
+        return np.asarray(a, *args, **kw)
+
+    def array(self, a, *args, **kw):
+        self._refuse(a)
+        return np.array(a, *args, **kw)
+
+    def concatenate(self, arrays, *args, **kw):
+        self._refuse(arrays)
+        return np.concatenate(arrays, *args, **kw)
+
+
+def _forbid_device_to_host(monkeypatch):
+    from repro.core import kvtransfer, offload_engine
+    from repro.kernels.wr_scatter import ops
+    from repro.verbs import transport
+    guarded = _NumpyWithoutDeviceArrays()
+    for mod in (transport, offload_engine, ops, kvtransfer):
+        monkeypatch.setattr(mod, "np", guarded)
+
+
+@pytest.mark.parametrize("k", [1, 3, 5, 8, 17])
+def test_migrate_pages_keeps_page_bytes_on_device(gemma, monkeypatch, k):
+    """A k-page migration lands bit-exact in the decode pool's MRs with
+    no device->host copy: each leaf run's gathered block is the DMA's
+    buffer (one device-path sub-run per leaf, none on the host path),
+    whether or not k is a power of two."""
+    model, params = gemma
+    fabric = verbs.Fabric(pods=2)
+    eng = ServeEngine(model, params, max_batch=1, max_seq=256,
+                      fabric=fabric, gid="pod1/dev0",
+                      service="serve/pod1/dev0", page_tokens=8)
+    pod = PrefillPod(model, params, fabric=fabric, gid="pod0/dev0",
+                     decode_gids=["pod1/dev0"], max_seq=256, page_tokens=8)
+    rng = np.random.default_rng(k)
+    pod.pool.rebind([jnp.asarray(rng.standard_normal(r.shape), r.dtype)
+                     for r in pod.pool.regions()])
+    src_ids = pod.pool.alloc(k)
+    dst_ids = eng.pool.alloc(k)
+    runs = [(mr, src_ids, rkey, dst) for mr, (rkey, dst)
+            in zip(pod.pool.mrs, eng.pool.lease(dst_ids))]
+    want = [np.asarray(r)[src_ids] for r in pod.pool.regions()]
+    fused = metrics.get_registry().scope("fused")
+    dev0 = fused.counter("device_write_runs").value
+    host0 = fused.counter("host_write_runs").value
+    nbytes0 = fused.counter("device_write_bytes").value
+    _forbid_device_to_host(monkeypatch)
+    with jax.transfer_guard_device_to_host("disallow"):
+        pod.kv.migrate_pages(runs)
+    monkeypatch.undo()
+    n_leaf_runs = len(pod.pool.mrs)
+    assert fused.counter("device_write_runs").value - dev0 == n_leaf_runs
+    assert fused.counter("host_write_runs").value == host0
+    assert fused.counter("device_write_bytes").value - nbytes0 == \
+        sum(w.nbytes for w in want)
+    for w, dst_r in zip(want, eng.pool.regions()):
+        np.testing.assert_array_equal(np.asarray(dst_r)[dst_ids], w)
+    # landed blocks are released, not pinned by the decode QP's DMA queue
+    assert all(d.buf is None for d in pod.kv.ep.peer.qp.ctx._dma_queue)
+    pod.close()
+    eng.close()
+
+
+@pytest.mark.slow
+def test_migrate_pages_across_devices_stays_on_device():
+    """On a physically backed two-device grid (each pod's pool on its own
+    device, `Fabric.device_of`), every leaf run still takes the device
+    path: its block moves to the decode pool's device and lands there
+    bit-exact."""
+    prog = textwrap.dedent("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        import jax, jax.numpy as jnp, numpy as np
+        from repro import verbs
+        from repro.configs.base import get_config, reduced
+        from repro.models.registry import build_model
+        from repro.obs import metrics
+        from repro.serve.engine import ServeEngine
+        from repro.serve.pd_disagg import PrefillPod
+
+        model = build_model(reduced(get_config("gemma-2b")))
+        params = model.init(jax.random.PRNGKey(0))
+        fabric = verbs.Fabric(pods=2)
+        eng = ServeEngine(model, params, max_batch=1, max_seq=64,
+                          fabric=fabric, gid="pod1/dev0",
+                          service="serve/pod1/dev0", page_tokens=8)
+        pod = PrefillPod(model, params, fabric=fabric, gid="pod0/dev0",
+                         decode_gids=["pod1/dev0"], max_seq=64,
+                         page_tokens=8)
+        src_dev, dst_dev = jax.devices()
+        rng = np.random.default_rng(0)
+        pod.pool.rebind([jax.device_put(
+            jnp.asarray(rng.standard_normal(r.shape), r.dtype), src_dev)
+            for r in pod.pool.regions()])
+        src_ids, dst_ids = pod.pool.alloc(5), eng.pool.alloc(5)
+        runs = [(mr, src_ids, rkey, dst) for mr, (rkey, dst)
+                in zip(pod.pool.mrs, eng.pool.lease(dst_ids))]
+        want = [np.asarray(r)[src_ids] for r in pod.pool.regions()]
+        fused = metrics.get_registry().scope("fused")
+        pod.kv.migrate_pages(runs)
+        assert fused.counter("device_write_runs").value == len(runs)
+        assert fused.counter("host_write_runs").value == 0
+        for w, r in zip(want, eng.pool.regions()):
+            assert r.devices() == {dst_dev}, r.devices()
+            np.testing.assert_array_equal(np.asarray(r)[dst_ids], w)
+        print("OK")
+    """)
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    r = subprocess.run([sys.executable, "-c", prog], capture_output=True,
+                       text=True, timeout=600, env=env)
+    assert r.returncode == 0 and "OK" in r.stdout, \
+        f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr}"
+
+
+def test_migrated_pages_survive_source_reuse(gemma):
+    """Nothing syncs between a migration's gather and its landing, so a
+    source page freed and refilled with another request's caches right
+    after `migrate_pages` returns must not reach the decode pool: the
+    pool still holds the first request's pages, and decoding from them
+    reproduces the single-pod oracle."""
+    model, params = gemma
+    fabric = verbs.Fabric(pods=2)
+    eng = ServeEngine(model, params, max_batch=2, max_seq=64,
+                      fabric=fabric, gid="pod1/dev0",
+                      service="serve/pod1/dev0", page_tokens=8)
+    pod = PrefillPod(model, params, fabric=fabric, gid="pod0/dev0",
+                     decode_gids=["pod1/dev0"], max_seq=64, page_tokens=8)
+    router = Router(fabric)
+    router.add_decode(eng)
+    router.add_prefill(pod)
+    _, other = pod._run_prefill(np.arange(40, 63, dtype=np.int32))
+    migrate = pod.kv.migrate_pages
+    checked = []
+
+    def migrate_then_reuse(runs, **kw):
+        gid = migrate(runs, **kw)
+        _, src_ids, _, _ = runs[0]
+        want = [np.asarray(r)[src_ids] for r in pod.pool.regions()]
+        pod.pool.free(src_ids)
+        again = pod.pool.alloc(len(src_ids))
+        assert sorted(again) == sorted(src_ids)
+        pod.pool.fill(src_ids, other)
+        for w, r, run in zip(want, eng.pool.regions(), runs):
+            np.testing.assert_array_equal(np.asarray(r)[run[3]], w)
+        checked.append(len(src_ids))
+        return gid
+    pod.kv.migrate_pages = migrate_then_reuse
+    prompts = PROMPTS[:3] + [list(range(3, 23))]      # 1 and 3 pages
+    rids = [router.submit(p, max_new_tokens=6) for p in prompts]
+    res = router.run_until_done()
+    assert checked == [1, 1, 1, 3]
+    exp = _oracle(model, params, prompts)
+    for r, e in zip(rids, exp):
+        assert res[r] == e, (r, res[r], e)
+    router.close()
 
 
 # -- the cluster ---------------------------------------------------------

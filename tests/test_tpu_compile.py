@@ -90,6 +90,24 @@ def test_page_gather_compiles_past_int32_elements(one_chip):
     assert mem.output_size_in_bytes == 32 * _record_bytes("bfloat16")
 
 
+def test_page_gather_keeps_record_shape(one_chip):
+    """Given the record shape, `gather_records` returns whole pages in
+    that shape from the one `jit__gather` program: the block a
+    device-to-device WRITE hands to the page scatter, with no
+    temporary of a record's size."""
+    region = _shape(one_chip, (POOL_PAGES,) + PAGE, "bfloat16")
+    offs = _shape(one_chip, (32,), "int32")
+    out = jax.eval_shape(
+        lambda r, o: wr_ops._gather(r, o, length=PAGE),
+        jax.ShapeDtypeStruct(region.shape, region.dtype),
+        jax.ShapeDtypeStruct(offs.shape, offs.dtype))
+    assert out.shape == (32,) + PAGE
+    mem = wr_ops._gather.lower(region, offs, length=PAGE).compile() \
+        .memory_analysis()
+    assert mem.output_size_in_bytes == 32 * _record_bytes("bfloat16")
+    assert mem.temp_size_in_bytes < _record_bytes("bfloat16")
+
+
 def test_desc_ring_compiles_at_auto_depth(one_chip):
     """The device-resident descriptor ring at the TPU auto-residency
     depth (`DEVICE_RING_AUTO_DEPTH`), fused publish + poll."""
