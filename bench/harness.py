@@ -14,6 +14,7 @@ the cell asks for: there is no fallback to the CPU.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import math
 import shutil
@@ -54,27 +55,98 @@ _FIELDS = {"n_layers": "num_hidden_layers", "d_model": "hidden_size",
            "d_ff": "intermediate_size", "vocab_size": "vocab_size",
            "rope_theta": "rope_theta", "norm_eps": "rms_norm_eps",
            "tie_embeddings": "tie_word_embeddings", "dtype": "torch_dtype"}
+# more of them, by dotted path, for each part a registry entry may have
+# beyond a GQA block: sparse experts, latent attention, multi-token
+# prediction. A file states keys of a part exactly when its entry has it.
+_PARTS = {
+    "moe": {"moe.n_experts": "n_routed_experts",
+            "moe.top_k": "num_experts_per_tok",
+            "moe.d_ff_expert": "moe_intermediate_size",
+            "moe.d_ff_shared": "moe_intermediate_size",
+            "d_ff": "moe_intermediate_size",    # the program's expert width
+            "moe.n_shared": "n_shared_experts",
+            "moe.first_dense": "first_k_dense_replace",
+            "moe.d_ff_dense": "intermediate_size"},  # the dense layers'
+    "mla": {f"mla.{k}": k for k in ("q_lora_rank", "kv_lora_rank",
+                                    "qk_nope_head_dim", "qk_rope_head_dim",
+                                    "v_head_dim")},
+    "mtp_depth": {"mtp_depth": "num_nextn_predict_layers"},
+}
 
 
-def program_model(cfg: dict):
-    """The program's model, built from its registry entry with every
-    size the configuration file states; a registry value that differs
-    from the file is logged."""
-    from repro.configs.base import get_config
-    from repro.models.registry import build_model
-    base = get_config(cfg["program_arch"])
-    kw = {f: type(getattr(base, f))(cfg[k]) for f, k in _FIELDS.items()}
-    for f, v in kw.items():
-        if getattr(base, f) != v:
-            log(f"{cfg['program_arch']}: {f} {getattr(base, f)!r} in the "
-                f"program's registry, {v!r} in {cfg['name']}'s file")
+def _replace(obj, path: str, value):
+    """`obj` with the scalar field at dotted `path` set to `value`, in
+    the field's own type."""
+    names = path.split(".")
+    chain = [obj]
+    for n in names:
+        o = chain[-1]
+        if not dataclasses.is_dataclass(o) or n not in {
+                f.name for f in dataclasses.fields(o)}:
+            raise KeyError(f"no ModelConfig field {path!r}")
+        chain.append(getattr(o, n))
+    old = chain.pop()
+    if not isinstance(old, (bool, int, float, str)):
+        raise KeyError(f"ModelConfig field {path!r} is not a size")
+    new = type(old)(value)
+    if new != value:
+        raise ValueError(f"ModelConfig field {path!r}: {value!r} is not a "
+                         f"{type(old).__name__}")
+    for o, n in zip(reversed(chain), reversed(names)):
+        new = dataclasses.replace(o, **{n: new})
+    return new
+
+
+def _get(obj, path: str):
+    for name in path.split("."):
+        obj = getattr(obj, name)
+    return obj
+
+
+def program_config(cfg: dict):
+    """The program's ModelConfig: its registry entry with every size the
+    configuration file states, by published key (`_FIELDS`, `_PARTS`)
+    and then by dotted field (``program_fields``). A registry value the
+    file leaves unstated, differs from or sets by field is logged."""
+    from repro.configs.base import FrontendConfig, get_config
+    arch, name = cfg["program_arch"], cfg["name"]
+    base = get_config(arch)
+    fields = dict(_FIELDS)
+    for part, table in _PARTS.items():
+        stated = sorted((set(table.values()) - set(_FIELDS.values()))
+                        & cfg.keys())
+        if bool(getattr(base, part)) != bool(stated):
+            raise ValueError(
+                f"the configuration and the program disagree on the block: "
+                f"{arch} {'has' if getattr(base, part) else 'lacks'} "
+                f"{part}, {name}'s file states {stated or 'none of its keys'}")
+        if stated:
+            fields.update(table)
     if base.act != "swiglu" or base.qkv_bias or cfg["hidden_act"] != "silu":
         raise ValueError("the configuration and the program disagree on "
                          "the block")
     # the served model is text-only: no modality frontend
-    from repro.configs.base import FrontendConfig
-    return build_model(dataclasses.replace(base, frontend=FrontendConfig(),
-                                           **kw))
+    out = dataclasses.replace(base, frontend=FrontendConfig())
+    for f, k in fields.items():
+        if k not in cfg:
+            log(f"{arch}: {f} not stated in {name}'s file; the registry's "
+                f"{_get(base, f)!r} kept")
+            continue
+        out = _replace(out, f, cfg[k])
+        if _get(base, f) != _get(out, f):
+            log(f"{arch}: {f} {_get(base, f)!r} in the program's registry, "
+                f"{_get(out, f)!r} in {name}'s file")
+    for f, v in cfg.get("program_fields", {}).items():
+        out = _replace(out, f, v)
+        log(f"{arch}: {f} {_get(base, f)!r} in the program's registry, "
+            f"{_get(out, f)!r} by {name}'s program_fields")
+    return out
+
+
+def program_model(cfg: dict):
+    """The program's model of the configuration file `cfg`."""
+    from repro.models.registry import build_model
+    return build_model(program_config(cfg))
 
 
 class DeviceTrace:
@@ -112,11 +184,16 @@ class DeviceTrace:
 class Context:
     """What a per-layer metric reader may read."""
     cfg: dict
-    shapes: work.Shapes
     peaks: dict | None
     window: session.Window
     spans: session.Spans
     trace: device_trace.Summary | None
+
+    @functools.cached_property
+    def shapes(self) -> work.Shapes:
+        """The GQA block's work counts, built when a reader first asks,
+        so a run of another block fails only where a reader needs them."""
+        return work.Shapes(self.cfg)
 
     def spans_in_window(self, name: str) -> list:
         return self.spans.between(name, self.window.start, self.window.end)
@@ -165,7 +242,7 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool,
     clock = session.CompileClock()
     marks = [("start", time.perf_counter())]
     model = program_model(cfg)
-    params = weights.make(model, seed)
+    params = weights.make(model, seed, cfg)
     jax.block_until_ready(params)
     marks.append(("weights", time.perf_counter()))
     tr = traffic.generate(cell.mix, vocab=int(cfg["vocab_size"]), seed=seed,
@@ -220,8 +297,7 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool,
         log(f"lowered inside the window: {win.compiled_names}")
 
     if trace:
-        ctx = Context(cfg, work.Shapes(cfg), peak, win, spans,
-                      win.trace_summary)
+        ctx = Context(cfg, peak, win, spans, win.trace_summary)
         values = {m.name: cell.readers[m.name](ctx) for m in cell.per_layer}
         metrics = {m.name: {"value": values[m.name], "unit": m.unit}
                    for m in cell.per_layer if values[m.name] is not None}

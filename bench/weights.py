@@ -4,9 +4,12 @@ type they are served in.
 The tree has the program's layout (its leaf names and shapes, read
 with `jax.eval_shape` of its own init, which allocates nothing); the
 values are the benchmark's own. Each leaf's rule is looked up by its
-path in `RULES`, so a leaf the benchmark does not know is an error,
-not a silent default. The plain reference reads the same tree by the
-same names.
+last two path names in `RULES`, then in the configuration file's own
+``weight_rules`` (the same form, keyed ``"a/b"``), so a configuration
+whose block has leaves the benchmark does not know brings their rules
+as data. A leaf found in neither is an error, not a silent default; so
+is a file rule that redefines one of `RULES` or that no leaf uses. The
+plain reference reads the same tree by the same names.
 """
 from __future__ import annotations
 
@@ -35,6 +38,7 @@ RULES = {
     ("up", "w"): ("normal", (-2,)),
     ("down", "w"): ("normal", (-2,)),       # (..., F, D)
 }
+INITS = ("normal", "ones", "zeros")
 
 
 def _names(path) -> tuple[str, ...]:
@@ -45,12 +49,40 @@ def _names(path) -> tuple[str, ...]:
     return tuple(out)
 
 
-def rule(path) -> tuple[str, tuple | None]:
-    names = _names(path)
-    r = RULES.get(names[-2:])
-    if r is None:
-        raise KeyError(f"no weight rule for leaf {'/'.join(names)}")
-    return r
+def file_rules(cfg: dict) -> dict:
+    """The configuration file's ``weight_rules``, keyed as `RULES` is."""
+    out = {}
+    for name, (init, fan_dims) in cfg.get("weight_rules", {}).items():
+        key = tuple(name.split("/"))
+        if len(key) != 2:
+            raise ValueError(f"weight rule {name!r}: not two path names")
+        if key in RULES:
+            raise ValueError(f"weight rule {name!r} in the configuration "
+                             f"file redefines the benchmark's own")
+        if init not in INITS or (init == "normal") != bool(fan_dims):
+            raise ValueError(f"weight rule {name!r}: {init!r} with fan-in "
+                             f"dims {fan_dims!r}")
+        out[key] = (init, tuple(fan_dims) if fan_dims else None)
+    return out
+
+
+def rules(leaves, cfg: dict) -> list[tuple[str, tuple | None]]:
+    """Each leaf's rule, from `RULES` and then the file's own."""
+    extra = file_rules(cfg)
+    out, used = [], set()
+    for path, _ in leaves:
+        names = _names(path)
+        r = RULES.get(names[-2:])
+        if r is None:
+            r = extra.get(names[-2:])
+            used.add(names[-2:])
+        if r is None:
+            raise KeyError(f"no weight rule for leaf {'/'.join(names)}")
+        out.append(r)
+    unused = sorted("/".join(k) for k in extra.keys() - used)
+    if unused:
+        raise ValueError(f"weight rules no leaf uses: {unused}")
+    return out
 
 
 def key_data(seed: int, stream: int) -> np.ndarray:
@@ -59,20 +91,21 @@ def key_data(seed: int, stream: int) -> np.ndarray:
         4, np.uint32)
 
 
-def make(model, seed: int):
+def make(model, seed: int, cfg: dict):
     """The program's parameter tree filled from `seed`, in one jitted
-    call on the default device."""
+    call on the default device; `cfg` is the configuration file."""
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
-    rules = [rule(p) for p, _ in leaves]
+    leaf_rules = rules(leaves, cfg)
 
     def build(kd):
         keys = jax.random.split(jax.random.wrap_key_data(kd, impl="rbg"),
                                 len(leaves))
         out = []
-        for (_, sd), (init, fan_dims), k in zip(leaves, rules, keys):
-            if init == "ones":
-                out.append(jnp.ones(sd.shape, sd.dtype))
+        for (_, sd), (init, fan_dims), k in zip(leaves, leaf_rules, keys):
+            if init != "normal":
+                fill = jnp.ones if init == "ones" else jnp.zeros
+                out.append(fill(sd.shape, sd.dtype))
                 continue
             fan = int(np.prod([sd.shape[d] for d in fan_dims]))
             x = jax.random.normal(k, sd.shape, jnp.float32) * fan ** -0.5
